@@ -1,10 +1,9 @@
-"""Wall-clock microbenchmarks (XLA:CPU): the measured-path evidence that the
-suite's problem interface also drives real timers, not only the analytical
-model.  Times the jnp reference implementation of each kernel at a reduced
-shape, plus one Pallas interpret-mode call for parity checking.
+"""Wall-clock microbenchmarks of each kernel's jnp reference at a reduced
+shape, on the default JAX backend (XLA:CPU on a host without a TPU).
 
-On TPU hardware the same harness times the compiled Pallas kernels; the
-evaluator is selected by backend (see core/problem.MeasuredProblem)."""
+These time the reference, never a Pallas kernel: they are host numbers,
+not device measurements.  Kernels are measured on the chip through
+``KernelProblem.measured`` (see ``chip_smoke.py``)."""
 
 from __future__ import annotations
 

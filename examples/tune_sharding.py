@@ -46,7 +46,8 @@ def objective(config, arch_name: str) -> float:
     cfg = dataclasses.replace(cfg, remat=bool(config["remat"]))
     model_ways = config["model_ways"]
     mesh = jax.make_mesh((N_DEV // model_ways, model_ways),
-                         ("data", "model"))
+                         ("data", "model"),
+                         axis_types=(jax.sharding.AxisType.Auto,) * 2)
     try:
         plan = plan_cell(cfg, "train_4k", mesh,
                          microbatches=config["microbatches"])

@@ -398,10 +398,18 @@ class FunctionProblem(TunableProblem):
 
 
 class MeasuredProblem(TunableProblem):
-    """Wall-clock measurement of a callable built from a config (XLA:CPU).
+    """Wall-clock measurement, on the device, of a callable built from a
+    config.
 
-    Used by the micro-benchmark harness; analytical studies use the cost
-    model instead (deterministic, full-space-enumerable).
+    ``build(config)`` compiles ahead of time (``jax.jit(...).lower(...)
+    .compile()``) and returns a zero-argument callable that runs the
+    compiled program; ``KernelProblem.measured`` provides one for every
+    kernel.  A build that raises — the chip's compiler refusing the config
+    — is one invalid trial carrying the error, never a retry.  The
+    objective is the best of ``repeats`` timings, each ending in
+    ``jax.block_until_ready`` so that it covers the device's work and not
+    only the enqueue.  Analytical studies use the cost model instead
+    (deterministic, full-space-enumerable).
     """
 
     def __init__(self, space: SearchSpace,
@@ -413,7 +421,8 @@ class MeasuredProblem(TunableProblem):
         self.repeats = repeats
         self.warmup = warmup
 
-    def evaluate(self, config: Config, arch: str = "cpu") -> Trial:
+    def evaluate(self, config: Config, arch: str = DEFAULT_ARCH) -> Trial:
+        import jax
         if not self.space.satisfies(config):
             return Trial(config, math.inf, arch, valid=False)
         # the compile-vs-measure split: one span per phase so a trace
@@ -423,16 +432,16 @@ class MeasuredProblem(TunableProblem):
         try:
             with span("kernel.build", cat="kernel", arch=arch):
                 fn = self.build(config)
-        except Exception as e:  # config that fails to build == invalid
+        except Exception as e:  # config that fails to compile == invalid
             return Trial(config, math.inf, arch, valid=False,
                          info={"error": repr(e)})
         with span("kernel.measure", cat="kernel", arch=arch,
                   repeats=self.repeats):
             for _ in range(self.warmup):
-                fn()
+                jax.block_until_ready(fn())
             best = math.inf
             for _ in range(self.repeats):
                 t0 = time.perf_counter()
-                fn()
+                jax.block_until_ready(fn())
                 best = min(best, time.perf_counter() - t0)
         return Trial(config, best, arch, valid=True)

@@ -84,13 +84,8 @@ def pipeline_apply(stage_fn: Callable, mesh: Mesh, *, axis: str = "stage",
 
     in_specs = (P(axis), P())      # params stacked over stage; x replicated
     out_specs = P()
-    if hasattr(jax, "shard_map"):
-        f = jax.shard_map(per_stage, mesh=mesh, in_specs=in_specs,
-                          out_specs=out_specs, check_vma=False)
-    else:  # pre-0.4.38: experimental namespace, check_rep instead of check_vma
-        from jax.experimental.shard_map import shard_map
-        f = shard_map(per_stage, mesh=mesh, in_specs=in_specs,
-                      out_specs=out_specs, check_rep=False)
+    f = jax.shard_map(per_stage, mesh=mesh, in_specs=in_specs,
+                      out_specs=out_specs, check_vma=False)
 
     def apply(stacked_params, x):
         if x.shape[0] % 1:

@@ -37,10 +37,7 @@ DATA_PREFS = ("embed", "ff", "vocab", "heads_flat", "q_lora", "kv_lora")
 def use_mesh(mesh: Mesh):
     token = _MESH.set(mesh)
     try:
-        # jax.set_mesh landed in 0.4.38; older jax enters the mesh directly
-        # (the pre-0.4.38 context API), which sets the same ambient mesh.
-        ctx = jax.set_mesh(mesh) if hasattr(jax, "set_mesh") else mesh
-        with ctx:
+        with jax.set_mesh(mesh):
             yield mesh
     finally:
         _MESH.reset(token)

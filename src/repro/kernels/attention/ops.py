@@ -1,32 +1,16 @@
-"""Public flash-attention op: backend dispatch + tuned-config defaults.
-
-This is the kernel the LM stack (repro.models.attention) deploys on TPU;
-the jnp reference path is what the dry-run lowers (XLA handles the sharded
-softmax), keeping the two behind one interface.
-"""
+"""Public flash-attention op: the Pallas kernel with tuned-config defaults."""
 
 from __future__ import annotations
 
-import jax
-
 from .kernel import flash_attention as flash_pallas
-from .ref import mha_reference
 
 DEFAULT_CONFIG = {"block_q": 256, "block_kv": 512, "block_h": 4,
                   "skip_masked": 1, "acc_dtype": "f32"}
 
 
 def attention(q, k, v, *, causal=True, scale=None, config: dict | None = None,
-              use_pallas: bool | None = None, interpret: bool | None = None):
+              interpret: bool = False):
     """``q``: (Hq, Tq, D); ``k``/``v``: (Hkv, Tk, D) -> (Hq, Tq, D)."""
-    if use_pallas is None:
-        use_pallas = jax.default_backend() == "tpu"
-    if not use_pallas:
-        return mha_reference(q, k, v, causal=causal, scale=scale)
-    cfg = dict(DEFAULT_CONFIG)
-    if config:
-        cfg.update(config)
-    if interpret is None:
-        interpret = jax.default_backend() != "tpu"
+    cfg = {**DEFAULT_CONFIG, **(config or {})}
     return flash_pallas(q, k, v, causal=causal, scale=scale,
                         interpret=interpret, **cfg)

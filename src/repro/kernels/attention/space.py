@@ -133,7 +133,7 @@ class AttentionProblem(KernelProblem):
         return ref.mha_reference(inputs["q"], inputs["k"], inputs["v"],
                                  causal=inputs["causal"])
 
-    def run_kernel(self, config: Config, inputs: dict, interpret: bool = True):
+    def run_kernel(self, config: Config, inputs: dict, *, interpret: bool):
         return kernel.flash_attention(inputs["q"], inputs["k"], inputs["v"],
                                       causal=inputs["causal"],
                                       interpret=interpret, **config)

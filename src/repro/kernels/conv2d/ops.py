@@ -1,11 +1,8 @@
-"""Public conv2d op with backend dispatch."""
+"""Public conv2d op: the Pallas kernel with tuned-config defaults."""
 
 from __future__ import annotations
 
-import jax
-
 from .kernel import conv2d as conv2d_pallas
-from .ref import conv2d_reference
 
 DEFAULT_CONFIG = {
     "block_h": 64, "block_w": 1024, "unroll_fh": 5, "unroll_fw": 5,
@@ -14,15 +11,7 @@ DEFAULT_CONFIG = {
 
 
 def conv2d(image, filt, config: dict | None = None,
-           use_pallas: bool | None = None, interpret: bool | None = None):
-    if use_pallas is None:
-        use_pallas = jax.default_backend() == "tpu"
-    if not use_pallas:
-        return conv2d_reference(image, filt)
-    cfg = dict(DEFAULT_CONFIG)
-    if config:
-        cfg.update(config)
+           interpret: bool = False):
+    cfg = {**DEFAULT_CONFIG, **(config or {})}
     cfg["filter_smem"] = bool(cfg["filter_smem"])
-    if interpret is None:
-        interpret = jax.default_backend() != "tpu"
     return conv2d_pallas(image, filt, interpret=interpret, **cfg)

@@ -157,7 +157,7 @@ class Conv2dProblem(KernelProblem):
     def run_reference(self, config: Config, inputs: dict):
         return ref.conv2d_reference(inputs["image"], inputs["filt"])
 
-    def run_kernel(self, config: Config, inputs: dict, interpret: bool = True):
+    def run_kernel(self, config: Config, inputs: dict, *, interpret: bool):
         cfg = dict(config)
         cfg["filter_smem"] = bool(cfg.get("filter_smem", 0))
         return kernel.conv2d(inputs["image"], inputs["filt"],

@@ -2,10 +2,7 @@
 
 from __future__ import annotations
 
-import jax
-
 from .kernel import dedisp as dedisp_pallas
-from .ref import dedisp_reference
 
 DEFAULT_CONFIG = {
     "block_d": 64, "block_c": 4, "time_chunk": 0, "unroll_d": 1,
@@ -14,16 +11,8 @@ DEFAULT_CONFIG = {
 
 
 def dedisp(x, delays, t_out: int, config: dict | None = None,
-           use_pallas: bool | None = None, interpret: bool | None = None):
+           interpret: bool = False):
     """``x``: (C, T) channel samples; ``delays``: (C, D) int32 per-channel
     per-DM delays -> (D, t_out) dedispersed series."""
-    if use_pallas is None:
-        use_pallas = jax.default_backend() == "tpu"
-    if not use_pallas:
-        return dedisp_reference(x, delays, t_out)
-    cfg = dict(DEFAULT_CONFIG)
-    if config:
-        cfg.update(config)
-    if interpret is None:
-        interpret = jax.default_backend() != "tpu"
+    cfg = {**DEFAULT_CONFIG, **(config or {})}
     return dedisp_pallas(x, delays, t_out=t_out, interpret=interpret, **cfg)

@@ -141,7 +141,7 @@ class DedispProblem(KernelProblem):
         return ref.dedisp_reference(inputs["x"], inputs["delays"],
                                     inputs["t_out"])
 
-    def run_kernel(self, config: Config, inputs: dict, interpret: bool = True):
+    def run_kernel(self, config: Config, inputs: dict, *, interpret: bool):
         return kernel.dedisp(inputs["x"], inputs["delays"],
                              t_out=inputs["t_out"], interpret=interpret,
                              **config)

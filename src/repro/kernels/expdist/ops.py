@@ -2,10 +2,7 @@
 
 from __future__ import annotations
 
-import jax
-
 from .kernel import expdist as expdist_pallas
-from .ref import expdist_reference
 
 DEFAULT_CONFIG = {
     "block_i": 256, "block_j": 1024, "use_column": 0, "n_y_blocks": 1,
@@ -14,16 +11,8 @@ DEFAULT_CONFIG = {
 
 
 def expdist(a, b, sa, sb, config: dict | None = None,
-            use_pallas: bool | None = None, interpret: bool | None = None):
+            interpret: bool = False):
     """``a``/``b``: (2, K) localizations; ``sa``/``sb``: (K,) uncertainties
     -> scalar Gaussian-overlap distance."""
-    if use_pallas is None:
-        use_pallas = jax.default_backend() == "tpu"
-    if not use_pallas:
-        return expdist_reference(a, b, sa, sb)
-    cfg = dict(DEFAULT_CONFIG)
-    if config:
-        cfg.update(config)
-    if interpret is None:
-        interpret = jax.default_backend() != "tpu"
+    cfg = {**DEFAULT_CONFIG, **(config or {})}
     return expdist_pallas(a, b, sa, sb, interpret=interpret, **cfg)

@@ -151,6 +151,6 @@ class ExpdistProblem(KernelProblem):
         return ref.expdist_reference(inputs["a"], inputs["b"],
                                      inputs["sa"], inputs["sb"])
 
-    def run_kernel(self, config: Config, inputs: dict, interpret: bool = True):
+    def run_kernel(self, config: Config, inputs: dict, *, interpret: bool):
         return kernel.expdist(inputs["a"], inputs["b"], inputs["sa"],
                               inputs["sb"], interpret=interpret, **config)

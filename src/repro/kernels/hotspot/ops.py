@@ -1,11 +1,8 @@
-"""Public hotspot op with backend dispatch."""
+"""Public hotspot op: the Pallas kernel with tuned-config defaults."""
 
 from __future__ import annotations
 
-import jax
-
 from .kernel import hotspot as hotspot_pallas
-from .ref import hotspot_reference
 
 DEFAULT_CONFIG = {
     "tt": 6, "block_h": 64, "block_w": 512, "unroll_t": 2,
@@ -14,14 +11,6 @@ DEFAULT_CONFIG = {
 
 
 def hotspot(temp, power, n_sweeps: int, config: dict | None = None,
-            use_pallas: bool | None = None, interpret: bool | None = None):
-    if use_pallas is None:
-        use_pallas = jax.default_backend() == "tpu"
-    if not use_pallas:
-        return hotspot_reference(temp, power, n_sweeps)
-    cfg = dict(DEFAULT_CONFIG)
-    if config:
-        cfg.update(config)
-    if interpret is None:
-        interpret = jax.default_backend() != "tpu"
+            interpret: bool = False):
+    cfg = {**DEFAULT_CONFIG, **(config or {})}
     return hotspot_pallas(temp, power, n_sweeps, interpret=interpret, **cfg)

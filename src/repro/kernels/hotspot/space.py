@@ -155,7 +155,7 @@ class HotspotProblem(KernelProblem):
         c = inputs["crop"]
         return out[c:-c, c:-c]
 
-    def run_kernel(self, config: Config, inputs: dict, interpret: bool = True):
+    def run_kernel(self, config: Config, inputs: dict, *, interpret: bool):
         cfg = {k: config[k] for k in
                ("tt", "block_h", "block_w", "unroll_t", "acc_dtype",
                 "keep_power_vmem", "grid_order")}

@@ -1,16 +1,8 @@
-"""Public GEMM op: backend dispatch + tuned-config defaults.
-
-On TPU the Pallas kernel runs compiled; on CPU (this container) the kernel is
-only available in interpret mode, so the default execution path is the XLA
-reference — the Pallas path stays selectable for tests and TPU deployment.
-"""
+"""Public GEMM op: the Pallas kernel with tuned-config defaults."""
 
 from __future__ import annotations
 
-import jax
-
 from .kernel import gemm as gemm_pallas
-from .ref import gemm_reference
 
 # tuned on the analytical v5e model (see benchmarks/data); refreshed by
 # `python -m benchmarks.tune_kernels`.
@@ -21,16 +13,8 @@ DEFAULT_CONFIG = {
 
 
 def gemm(a, b, c, alpha=1.0, beta=1.0, config: dict | None = None,
-         use_pallas: bool | None = None, interpret: bool | None = None):
-    if use_pallas is None:
-        use_pallas = jax.default_backend() == "tpu"
-    if not use_pallas:
-        return gemm_reference(a, b, c, alpha, beta)
-    cfg = dict(DEFAULT_CONFIG)
-    if config:
-        cfg.update(config)
-    if interpret is None:
-        interpret = jax.default_backend() != "tpu"
+         interpret: bool = False):
+    cfg = {**DEFAULT_CONFIG, **(config or {})}
     b_in = b if cfg["rhs_layout"] == "kn" else b.T
     return gemm_pallas(a, b_in, c, alpha=alpha, beta=beta,
                        interpret=interpret, **cfg)

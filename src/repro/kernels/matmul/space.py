@@ -183,7 +183,7 @@ class GemmProblem(KernelProblem):
         return ref.gemm_reference(inputs["a"], inputs["b"], inputs["c"],
                                   inputs["alpha"], inputs["beta"])
 
-    def run_kernel(self, config: Config, inputs: dict, interpret: bool = True):
+    def run_kernel(self, config: Config, inputs: dict, *, interpret: bool):
         a, b, c = inputs["a"], inputs["b"], inputs["c"]
         cfg = dict(config)
         m, k = a.shape

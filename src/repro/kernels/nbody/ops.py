@@ -1,11 +1,8 @@
-"""Public N-body op: backend dispatch + tuned-config defaults."""
+"""Public N-body op: the Pallas kernel with tuned-config defaults."""
 
 from __future__ import annotations
 
-import jax
-
 from .kernel import nbody as nbody_pallas
-from .ref import nbody_reference
 
 # tuned on the analytical v5e model; refreshed by benchmarks.tune_kernels.
 DEFAULT_CONFIG = {
@@ -15,15 +12,7 @@ DEFAULT_CONFIG = {
 
 
 def nbody(pos, mass, config: dict | None = None,
-          use_pallas: bool | None = None, interpret: bool | None = None):
+          interpret: bool = False):
     """``pos``: (3, N); ``mass``: (N,) -> (3, N) accelerations."""
-    if use_pallas is None:
-        use_pallas = jax.default_backend() == "tpu"
-    if not use_pallas:
-        return nbody_reference(pos, mass)
-    cfg = dict(DEFAULT_CONFIG)
-    if config:
-        cfg.update(config)
-    if interpret is None:
-        interpret = jax.default_backend() != "tpu"
+    cfg = {**DEFAULT_CONFIG, **(config or {})}
     return nbody_pallas(pos, mass, interpret=interpret, **cfg)

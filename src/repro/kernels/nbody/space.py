@@ -143,6 +143,6 @@ class NbodyProblem(KernelProblem):
     def run_reference(self, config: Config, inputs: dict):
         return ref.nbody_reference(inputs["pos"], inputs["mass"])
 
-    def run_kernel(self, config: Config, inputs: dict, interpret: bool = True):
+    def run_kernel(self, config: Config, inputs: dict, *, interpret: bool):
         return kernel.nbody(inputs["pos"], inputs["mass"],
                             interpret=interpret, **config)

@@ -1,11 +1,8 @@
-"""Public point-in-polygon op: backend dispatch + tuned-config defaults."""
+"""Public point-in-polygon op: the Pallas kernel with tuned-config defaults."""
 
 from __future__ import annotations
 
-import jax
-
 from .kernel import pnpoly as pnpoly_pallas
-from .ref import pnpoly_reference
 
 DEFAULT_CONFIG = {
     "block_points": 2048, "unroll_v": 4, "between_method": 0,
@@ -14,15 +11,7 @@ DEFAULT_CONFIG = {
 
 
 def pnpoly(points, poly, config: dict | None = None,
-           use_pallas: bool | None = None, interpret: bool | None = None):
+           interpret: bool = False):
     """``points``: (2, N); ``poly``: (2, V) -> int32 (1, N) inside flags."""
-    if use_pallas is None:
-        use_pallas = jax.default_backend() == "tpu"
-    if not use_pallas:
-        return pnpoly_reference(points, poly)
-    cfg = dict(DEFAULT_CONFIG)
-    if config:
-        cfg.update(config)
-    if interpret is None:
-        interpret = jax.default_backend() != "tpu"
+    cfg = {**DEFAULT_CONFIG, **(config or {})}
     return pnpoly_pallas(points, poly, interpret=interpret, **cfg)

@@ -128,7 +128,7 @@ class PnpolyProblem(KernelProblem):
     def run_reference(self, config: Config, inputs: dict):
         return ref.pnpoly_reference(inputs["points"], inputs["poly"])
 
-    def run_kernel(self, config: Config, inputs: dict, interpret: bool = True):
+    def run_kernel(self, config: Config, inputs: dict, *, interpret: bool):
         out = kernel.pnpoly(inputs["points"], inputs["poly"],
                             interpret=interpret, **config)
         return out[0]

@@ -36,6 +36,7 @@ def run_cell(arch: str, shape: str, multi_pod: bool, out_dir: Path,
              overrides: dict | None = None, probe: bool = False,
              optimized: bool = False, aspect: str | None = None) -> dict:
     import jax
+    from jax.sharding import AxisType
 
     from ..configs import ARCHS, SHAPES
     from ..roofline import analyze_compiled, model_flops, roofline_report
@@ -45,7 +46,8 @@ def run_cell(arch: str, shape: str, multi_pod: bool, out_dir: Path,
     cfg = ARCHS[arch]
     if aspect:          # §Perf: DPxTP aspect is itself a sharding tunable
         d, m = (int(x) for x in aspect.split("x"))
-        mesh = jax.make_mesh((d, m), ("data", "model"))
+        mesh = jax.make_mesh((d, m), ("data", "model"),
+                             axis_types=(AxisType.Auto,) * 2)
         mesh_name = f"{d}x{m}"
     else:
         mesh = make_production_mesh(multi_pod=multi_pod)

@@ -1,13 +1,17 @@
 """Worker pool: parallel config evaluation with fault isolation.
 
-Two execution modes, chosen automatically:
+Two execution modes for analytical problems:
 
-* ``thread`` — for analytical problems (the TPU cost model).  Chunks of the
-  batch go through ``TunableProblem.evaluate_many`` (the vectorized fast
-  path), one chunk per worker thread.
-* ``process`` — for :class:`MeasuredProblem` (wall-clock measurement), where
-  a worker can take down its interpreter (OOM, crashing kernel build) and
-  measurements must not contend on the GIL.  The problem must be picklable.
+* ``thread`` (the default) — chunks of the batch go through
+  ``TunableProblem.evaluate_many`` (the vectorized fast path), one chunk
+  per worker thread.
+* ``process`` — the same chunks in child processes.  The problem must be
+  picklable.
+
+A :class:`MeasuredProblem` takes neither: it is measured in the calling
+thread, one config at a time.  The device belongs to the process that
+holds it, so a child process cannot measure on it, and two configs timed
+at once would share it.  A process-mode pool refuses measured problems.
 
 Fault handling: a chunk that raises is retried config-by-config through a
 :class:`JobQueue`; a config that keeps raising past the retry cap is
@@ -47,6 +51,10 @@ class EvalCancelled(Exception):
     finishing the evaluation is pure waste.  Raised out of the pool's
     wait loops when the caller's cancel event is set."""
 
+
+_MEASURED_IN_CHILD = (
+    "a MeasuredProblem cannot run in process mode: it times kernels on the "
+    "device this process holds, and a child process cannot use that device")
 
 #: thread-mode minimum chunk size: splitting a small analytical batch
 #: across every worker forfeits the columnar evaluation path (below
@@ -99,9 +107,11 @@ class WorkerPool:
                  mode: str = "auto", max_retries: int = 2,
                  job_timeout_s: float | None = None):
         if mode == "auto":
-            mode = "process" if isinstance(problem, MeasuredProblem) else "thread"
+            mode = "thread"
         if mode not in ("thread", "process"):
             raise ValueError(f"unknown worker mode {mode!r}")
+        if mode == "process" and isinstance(problem, MeasuredProblem):
+            raise ValueError(_MEASURED_IN_CHILD)
         self.problem = problem
         self.arch = arch
         self.workers = max(1, int(workers))
@@ -169,9 +179,9 @@ class WorkerPool:
         rows = [int(r) for r in rows]
         if not rows:
             return []
-        if self.mode == "process":
-            # measured problems re-derive everything from configs anyway;
-            # keep one battle-tested path through the process pool
+        if self.mode == "process" or isinstance(problem, MeasuredProblem):
+            # process chunks and measured problems take configs: decode
+            # the rows once and go through evaluate
             cfgs = self._rows_to_configs(rows, problem)
             return self.evaluate(cfgs, arch, problem=problem, cancel=cancel)
         return self._evaluate_chunked(rows, arch or self.arch,
@@ -194,9 +204,43 @@ class WorkerPool:
         configs = list(configs)
         if not configs:
             return []
+        problem = problem or self.problem
+        if isinstance(problem, MeasuredProblem):
+            return self._measure(configs, arch or self.arch, problem, cancel)
         return self._evaluate_chunked(configs, arch or self.arch,
-                                      _evaluate_chunk, None,
-                                      problem or self.problem, cancel=cancel)
+                                      _evaluate_chunk, None, problem,
+                                      cancel=cancel)
+
+    def _measure(self, configs: list[Config], arch: str,
+                 problem: MeasuredProblem,
+                 cancel: threading.Event | None = None) -> list[Trial]:
+        """Measure ``configs`` in the calling thread, one at a time.
+
+        A config whose measurement raises is retried, then poisoned, as on
+        the pool's retry path; a config the compiler refuses is already an
+        invalid trial (``MeasuredProblem.evaluate``) and costs one compile.
+        The watchdog does not apply: a running measurement cannot be
+        interrupted from its own thread."""
+        if self.mode == "process":
+            raise ValueError(_MEASURED_IN_CHILD)
+        out: list[Trial] = []
+        with span("pool.evaluate", cat="pool", n=len(configs), arch=arch,
+                  mode="inline"):
+            for cfg in configs:
+                if cancel is not None and cancel.is_set():
+                    self.stats["cancelled"] += 1
+                    raise EvalCancelled("batch abandoned (lease lost)")
+                for attempt in range(1, self.max_retries + 2):
+                    try:
+                        out.append(_evaluate_one(problem, cfg, arch))
+                        break
+                    except Exception as e:
+                        err = repr(e)
+                else:
+                    out.append(Trial(cfg, math.inf, arch, valid=False,
+                                     info={"error": err, "poison": True,
+                                           "attempts": attempt}))
+        return out
 
     # -- arch-shared evaluation ------------------------------------------- #
     def _evaluate_rows_archs(self, rows: Sequence[int], archs: tuple[str, ...],
@@ -206,7 +250,7 @@ class WorkerPool:
         rows = [int(r) for r in rows]
         if not rows:
             return {a: [] for a in archs}
-        if self.mode == "process":
+        if self.mode == "process" or isinstance(problem, MeasuredProblem):
             # measured problems measure per architecture by definition —
             # there is nothing to share beyond the one decode
             cfgs = self._rows_to_configs(rows, problem)

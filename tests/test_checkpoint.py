@@ -75,7 +75,8 @@ def test_elastic_restore_with_shardings(tmp_path):
     """Restore placing leaves with explicit (different-mesh) shardings."""
     t = _tree()
     ckpt.save(tmp_path, 1, t)
-    mesh = jax.make_mesh((1,), ("data",))
+    mesh = jax.make_mesh((1,), ("data",),
+                         axis_types=(jax.sharding.AxisType.Auto,))
     sh = jax.tree.map(
         lambda _: jax.NamedSharding(mesh, jax.sharding.PartitionSpec()), t)
     got, _ = ckpt.restore(tmp_path, jax.eval_shape(lambda: t), shardings=sh)

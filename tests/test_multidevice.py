@@ -18,6 +18,7 @@ def _run(body: str, devices: int = 8, timeout: int = 420):
     prog = ("import os\n"
             f"os.environ['XLA_FLAGS'] = "
             f"'--xla_force_host_platform_device_count={devices}'\n"
+            "from jax.sharding import AxisType\n"
             + textwrap.dedent(body))
     env = dict(os.environ,
                PYTHONPATH=f"{REPO / 'src'}:{os.environ.get('PYTHONPATH', '')}")
@@ -52,7 +53,8 @@ def test_sharded_train_step_runs_and_matches_single_device():
     # single device reference
     p1, o1, m1 = jax.jit(step)(params, opt, batch)
 
-    mesh = jax.make_mesh((4, 2), ("data", "model"))
+    mesh = jax.make_mesh((4, 2), ("data", "model"),
+                         axis_types=(AxisType.Auto,) * 2)
     if model.axes is None:
         jax.eval_shape(model.init, jax.random.key(0))
     p_sh = shd.param_shardings(jax.eval_shape(lambda: params), model.axes,
@@ -82,7 +84,8 @@ def test_gpipe_pipeline_matches_serial():
     import jax, jax.numpy as jnp, numpy as np
     from repro.distributed.pipeline import bubble_fraction, pipeline_apply
 
-    mesh = jax.make_mesh((4, 2), ("stage", "data"))
+    mesh = jax.make_mesh((4, 2), ("stage", "data"),
+                         axis_types=(AxisType.Auto,) * 2)
     S, NM, MB, D = 4, 8, 4, 16
     ks = jax.random.split(jax.random.key(0), S)
     Ws = jnp.stack([jax.random.normal(k, (D, D)) * 0.3 for k in ks])
@@ -123,7 +126,8 @@ def test_dryrun_cell_on_8_devices():
 
     import dataclasses
     cfg = reduce_config(ARCHS["granite-moe-3b-a800m"])
-    mesh = jax.make_mesh((4, 2), ("data", "model"))
+    mesh = jax.make_mesh((4, 2), ("data", "model"),
+                         axis_types=(AxisType.Auto,) * 2)
     plan = plan_cell(cfg, "train_4k", mesh, microbatches=1)
     lowered = lower_cell(plan, mesh)
     compiled = lowered.compile()
@@ -152,14 +156,16 @@ def test_elastic_checkpoint_across_mesh_shapes():
     params = model.init(jax.random.key(0))
     jax.eval_shape(model.init, jax.random.key(0))
 
-    mesh1 = jax.make_mesh((4, 2), ("data", "model"))
+    mesh1 = jax.make_mesh((4, 2), ("data", "model"),
+                          axis_types=(AxisType.Auto,) * 2)
     sh1 = shd.param_shardings(jax.eval_shape(lambda: params), model.axes,
                               mesh1)
     p1 = jax.device_put(params, sh1)
     d = tempfile.mkdtemp()
     ckpt.save(d, 3, p1)
 
-    mesh2 = jax.make_mesh((2, 4), ("data", "model"))
+    mesh2 = jax.make_mesh((2, 4), ("data", "model"),
+                          axis_types=(AxisType.Auto,) * 2)
     sh2 = shd.param_shardings(jax.eval_shape(lambda: params), model.axes,
                               mesh2)
     like = jax.eval_shape(lambda: params)

@@ -488,7 +488,9 @@ def test_worker_pool_mode_selection():
     from repro.core.problem import MeasuredProblem
     space = SearchSpace([Param("a", (1, 2))], name="m")
     measured = MeasuredProblem(space, build=lambda cfg: (lambda: None))
-    assert WorkerPool(measured, "cpu").mode == "process"
+    assert WorkerPool(measured, "cpu").mode == "thread"
+    with pytest.raises(ValueError, match="process mode"):
+        WorkerPool(measured, "cpu", mode="process")
     assert WorkerPool(_quad_problem(), "v5e").mode == "thread"
     with pytest.raises(ValueError):
         WorkerPool(_quad_problem(), "v5e", mode="rayon")

@@ -41,7 +41,8 @@ def test_collective_bytes_parser():
 
 def test_collective_bytes_real_lowering():
     """An explicitly sharded psum must show up as all-reduce bytes."""
-    mesh = jax.make_mesh((1,), ("x",))
+    mesh = jax.make_mesh((1,), ("x",),
+                         axis_types=(jax.sharding.AxisType.Auto,))
     from jax.sharding import NamedSharding, PartitionSpec as P
 
     @jax.jit
