@@ -1,0 +1,9 @@
+"""Flash attention's share of its roofline: the least time its work needs
+at the chip's peaks (work/flash_attention.py, peaks.json) over the device
+time of one call in the trace."""
+
+from harness.readers import roofline_share
+
+
+def read(ctx):
+    return roofline_share(ctx, "flash_attention")
