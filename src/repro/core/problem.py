@@ -426,17 +426,20 @@ class MeasuredProblem(TunableProblem):
         if not self.space.satisfies(config):
             return Trial(config, math.inf, arch, valid=False)
         # the compile-vs-measure split: one span per phase so a trace
-        # shows where a measured config's wall-clock went.  Span overhead
+        # shows where a measured config's wall-clock went; both carry the
+        # config's key, which ties them together in a trace.  Span overhead
         # sits outside the per-repeat perf_counter windows, so enabling
         # tracing cannot bias the recorded objective.
+        key = "/".join(f"{k}={config[k]}" for k in sorted(config))
         try:
-            with span("kernel.build", cat="kernel", arch=arch):
+            with span("kernel.build", cat="kernel", arch=arch, key=key):
                 fn = self.build(config)
         except Exception as e:  # config that fails to compile == invalid
             return Trial(config, math.inf, arch, valid=False,
                          info={"error": repr(e)})
-        with span("kernel.measure", cat="kernel", arch=arch,
-                  repeats=self.repeats):
+        with span("kernel.measure", cat="kernel", arch=arch, key=key,
+                  repeats=self.repeats,
+                  calls=self.warmup + self.repeats) as s:
             for _ in range(self.warmup):
                 jax.block_until_ready(fn())
             best = math.inf
@@ -444,4 +447,5 @@ class MeasuredProblem(TunableProblem):
                 t0 = time.perf_counter()
                 jax.block_until_ready(fn())
                 best = min(best, time.perf_counter() - t0)
+            s.set(best_s=best)
         return Trial(config, best, arch, valid=True)

@@ -112,6 +112,7 @@ def flash_attention(q, k, v, *, causal=True, block_q=256, block_kv=512,
         skip_masked=skip_masked)
     return pl.pallas_call(
         kern,
+        name="flash_attention",
         grid=(hq // bh, cdiv(tq, bq), cdiv(tk, bkv)),
         in_specs=[
             pl.BlockSpec((bh, bq, d), lambda h, i, j: (h, i, 0)),

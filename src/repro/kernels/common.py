@@ -28,6 +28,7 @@ import numpy as np
 from ..core.costmodel import MiB
 from ..core.problem import MeasuredProblem, TunableProblem
 from ..core.space import Config, SearchSpace
+from ..telemetry.trace import span
 
 # Structural VMEM budget for space-level constraints: a config is kept in
 # the space if it could run on the LARGEST generation (128 MiB VMEM,
@@ -151,7 +152,12 @@ class KernelProblem(TunableProblem):
         return a zero-argument call of the compiled program.  Raises what
         the chip's compiler raises for a config it refuses."""
         arrays, _ = _split_inputs(inputs)
-        compiled = self.lower_kernel(config, inputs).compile()
+        # tracing and lowering hold the interpreter lock; the backend
+        # compile runs in native code
+        with span("kernel.lower", cat="kernel"):
+            lowered = self.lower_kernel(config, inputs)
+        with span("kernel.compile", cat="kernel"):
+            compiled = lowered.compile()
         return lambda: compiled(arrays)
 
     def measured(self, inputs: dict, **kw) -> MeasuredProblem:

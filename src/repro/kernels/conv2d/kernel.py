@@ -112,6 +112,7 @@ def conv2d(image, filt, *, block_h=32, block_w=512, unroll_fh=1, unroll_fw=1,
 
     out = pl.pallas_call(
         kern,
+        name="conv2d",
         grid=(gh * gw,),
         in_specs=[filt_spec,
                   pl.BlockSpec((1, th, tw), lambda g: (g, 0, 0))],

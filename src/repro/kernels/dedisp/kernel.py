@@ -106,6 +106,7 @@ def dedisp(x, delays, *, t_out, block_d=32, block_c=4, time_chunk=0,
     )
     out = pl.pallas_call(
         kern,
+        name="dedisp",
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((dp, t_out), jnp.float32),
         interpret=interpret,

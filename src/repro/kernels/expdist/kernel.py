@@ -102,6 +102,7 @@ def expdist(a, b, sa, sb, *, block_i=128, block_j=512, use_column=1,
 
     partials = pl.pallas_call(
         kern,
+        name="expdist",
         grid=(gi, gj),
         in_specs=[
             pl.BlockSpec((2, bi), lambda i, j: (0, i)),

@@ -94,6 +94,7 @@ def hotspot_step(temp, power, *, tt=2, block_h=64, block_w=512, unroll_t=1,
     grid = (gh * gw,)
     out = pl.pallas_call(
         kern,
+        name="hotspot",
         grid=grid,
         in_specs=[pl.BlockSpec((1, th, tw), lambda g: (g, 0, 0)),
                   pl.BlockSpec((1, th, tw), lambda g: (g, 0, 0))],

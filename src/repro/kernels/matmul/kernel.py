@@ -96,6 +96,7 @@ def gemm(a, b, c, *, block_m=128, block_n=128, block_k=512, unroll_k=1,
             swap = lambda f: f
         return pl.pallas_call(
             kern,
+            name="gemm",
             grid=grid,
             in_specs=[
                 pl.BlockSpec((block_m, block_k), swap(lambda i, j, kk: (i, kk))),

@@ -111,6 +111,7 @@ def nbody(pos, mass, *, block_i=128, block_j=1024, layout="soa", unroll_j=1,
     grid = (gi, gj)
     out = pl.pallas_call(
         kern,
+        name="nbody",
         grid=grid,
         in_specs=in_specs,
         out_specs=out_spec,

@@ -144,6 +144,7 @@ def pnpoly(points, poly, *, block_points=1024, unroll_v=4, between_method=0,
         block_pts=bp)
     out = pl.pallas_call(
         kern,
+        name="pnpoly",
         grid=grid,
         in_specs=[pl.BlockSpec((4, v), lambda g: (0, 0)), pts_spec],
         out_specs=pl.BlockSpec((1, bp), lambda g: (0, g)),

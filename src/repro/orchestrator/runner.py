@@ -157,9 +157,6 @@ def session_stepper(spec: SessionSpec, *, problem: TunableProblem,
     _slabel = spec.session_id
     _c_evals = _metrics.counter("session.evals", session=_slabel)
     _c_cache = _metrics.counter("session.cache_hits", session=_slabel)
-    _g_best = _metrics.gauge("session.best", session=_slabel)
-    _g_to_best = _metrics.gauge("session.evals_to_best", session=_slabel)
-    _best_seen = math.inf
     # index-native fast path: ask rows, dedup on the rows themselves (a row
     # *is* the flat index), evaluate through the pool's row path.  The ask
     # stream, batch widths, trajectories, and journal are identical to the
@@ -269,12 +266,6 @@ def session_stepper(spec: SessionSpec, *, problem: TunableProblem,
                 if screened:
                     _metrics.counter("session.screened",
                                      session=_slabel).inc(len(screened))
-                batch_best = min((t.objective for t in results if t.ok),
-                                 default=math.inf)
-                if batch_best < _best_seen:
-                    _best_seen = batch_best
-                    _g_best.set(batch_best)
-                    _g_to_best.set(len(res.trials))
 
             if store is not None:
                 b = res.best

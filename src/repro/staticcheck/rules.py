@@ -48,13 +48,13 @@ DOCUMENTED_SPANS = {
     "worker.job": "worker",
     "campaign.round": "campaign",
     "eval.features": "eval", "eval.estimate": "eval",
-    "kernel.build": "kernel", "kernel.measure": "kernel",
+    "kernel.build": "kernel", "kernel.lower": "kernel",
+    "kernel.compile": "kernel", "kernel.measure": "kernel",
 }
 
 #: metric names documented in the architecture.md metric table.
 DOCUMENTED_METRICS = frozenset({
-    "session.evals", "session.cache_hits", "session.best",
-    "session.evals_to_best",
+    "session.evals", "session.cache_hits",
     "space_cache.hit", "space_cache.miss",
     "journal.torn_lines",
     "servedb.lookup", "servedb.lookup_stale", "servedb.reload",

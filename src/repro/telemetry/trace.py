@@ -21,9 +21,18 @@ Cost model — the reason this can stay threaded through hot seams:
   check and the return of a shared no-op object — low hundreds of
   nanoseconds, measured by ``benchmarks/telemetry_bench.py``;
 * enabled: two ``perf_counter_ns`` calls plus one deque append per
-  span.  Instrumentation sits at *batch* granularity (an ask/tell, a
-  pool chunk, a journal write), never inside per-config loops, so the
-  enabled path stays within the benchmarked overhead bound.
+  span, and a ``jax.profiler.TraceAnnotation`` opened and closed around
+  it (about a microsecond with no profiler running).  Instrumentation
+  sits at *batch* granularity (an ask/tell, a pool chunk, a journal
+  write) or per compiled config (``kernel.*``), never inside per-config
+  loops, so the enabled path stays within the benchmarked overhead
+  bound.
+
+While tracing is enabled, each span is also written into a running JAX
+profiler's trace (the host plane of its ``.xplane.pb``) under its name
+and with its entry arguments, so spans share the clock of the device's
+operations.  ``jax`` is imported on :func:`enable`, never before; where
+it cannot be imported, spans go to the ring buffer alone.
 
 Tracing never draws randomness and never reorders work, so enabling it
 cannot perturb tuner trajectories (the rng-stream contract in
@@ -77,7 +86,7 @@ _NULL_SPAN = _NullSpan()
 class Span:
     """A live (enabled) span.  Use via :func:`span`, not directly."""
 
-    __slots__ = ("name", "cat", "args", "t0", "depth")
+    __slots__ = ("name", "cat", "args", "t0", "depth", "annotation")
     enabled = True
 
     def __init__(self, name: str, cat: str, args: dict):
@@ -91,15 +100,22 @@ class Span:
         return self
 
     def __enter__(self) -> "Span":
-        local = _TRACER.local
+        tracer = _TRACER
+        local = tracer.local
         self.depth = getattr(local, "depth", 0)
         local.depth = self.depth + 1
+        self.annotation = None
+        if tracer.annotate is not None:
+            self.annotation = tracer.annotate(self.name, **self.args)
+            self.annotation.__enter__()
         self.t0 = time.perf_counter_ns()
         return self
 
     def __exit__(self, exc_type, exc, tb):
         t1 = time.perf_counter_ns()
         tracer = _TRACER
+        if self.annotation is not None:
+            self.annotation.__exit__(exc_type, exc, tb)
         tracer.local.depth = self.depth
         if exc_type is not None:
             self.args["error"] = exc_type.__name__
@@ -115,6 +131,8 @@ class _Tracer:
 
     def __init__(self):
         self.enabled = False
+        #: ``jax.profiler.TraceAnnotation`` once enabled with jax present
+        self.annotate = None
         self.events: deque = deque(maxlen=DEFAULT_BUFFER)
         self.local = threading.local()
         self.origin_ns = time.perf_counter_ns()
@@ -126,6 +144,13 @@ class _Tracer:
         if not self.enabled:
             self.origin_ns = time.perf_counter_ns()
             self.origin_wall = time.time()
+        if self.annotate is None:
+            try:
+                from jax.profiler import TraceAnnotation
+            except ImportError:
+                pass
+            else:
+                self.annotate = TraceAnnotation
         self.enabled = True
 
     def disable(self) -> None:

@@ -131,6 +131,42 @@ def test_kernel_build_compiles_for_the_device(monkeypatch, tmp_path):
     assert "interpret" in t.info["error"]
 
 
+def test_kernel_build_records_lower_and_compile(monkeypatch, tmp_path):
+    """A compiled kernel's build is two child spans, lowering and the
+    backend compile; the CPU compiles the kernel lowered in interpret
+    mode."""
+    from repro.telemetry import trace as ttrace
+
+    def lower(self, config, inputs):
+        arrays, consts = common._split_inputs(inputs)
+        return jax.jit(lambda a: self.run_kernel(
+            config, {**consts, **a}, interpret=True)).lower(arrays)
+
+    monkeypatch.setattr(common.KernelProblem, "lower_kernel", lower)
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path))
+    prob = AttentionProblem({"hq": 4, "hkv": 2, "tq": 256, "tk": 256,
+                             "d": 64})            # the small inputs' shape
+    measured = prob.measured(prob.make_inputs(jax.random.key(0)),
+                             repeats=1, warmup=1)
+    config = prob.space.sample_distinct(1, seed=0)[0]
+    with ttrace.tracing():
+        t = measured.evaluate(config)
+        spans = ttrace.events()
+    assert t.valid, t.info
+    by = {e["name"]: e for e in spans}
+    build = by["kernel.build"]
+    for name in ("kernel.lower", "kernel.compile"):
+        child = by[name]
+        assert child["cat"] == "kernel"
+        assert child["depth"] == build["depth"] + 1
+        assert build["ts"] <= child["ts"]
+        assert child["ts"] + child["dur"] <= build["ts"] + build["dur"]
+    assert by["kernel.lower"]["ts"] < by["kernel.compile"]["ts"]
+    measure = by["kernel.measure"]["args"]
+    assert measure["key"] == build["args"]["key"]
+    assert measure["calls"] == 2 and measure["best_s"] == t.objective
+
+
 def test_compile_cache_honours_env_var(monkeypatch, tmp_path):
     saved = jax.config.jax_compilation_cache_dir
     monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path))
