@@ -10,8 +10,11 @@ exactly the interoperability argument of the paper.
 
 from __future__ import annotations
 
+import contextlib
 import math
+import threading
 import time
+from concurrent.futures import Executor, Future, ThreadPoolExecutor
 from typing import Any, Callable, Sequence
 
 from ..telemetry.trace import span
@@ -401,25 +404,92 @@ class MeasuredProblem(TunableProblem):
     """Wall-clock measurement, on the device, of a callable built from a
     config.
 
-    ``build(config)`` compiles ahead of time (``jax.jit(...).lower(...)
-    .compile()``) and returns a zero-argument callable that runs the
-    compiled program; ``KernelProblem.measured`` provides one for every
-    kernel.  A build that raises — the chip's compiler refusing the config
-    — is one invalid trial carrying the error, never a retry.  The
-    objective is the best of ``repeats`` timings, each ending in
-    ``jax.block_until_ready`` so that it covers the device's work and not
-    only the enqueue.  Analytical studies use the cost model instead
-    (deterministic, full-space-enumerable).
+    The build compiles ahead of time and gives a zero-argument callable
+    that runs the compiled program.  It comes in one of two forms:
+
+    * ``build(config)``, one callable that does the whole build;
+    * two stages: ``lower(config)``, the tracing and lowering, which hold
+      the interpreter lock and run on the caller's thread, and
+      ``compile(lowered)``, the backend compile, which runs in native code
+      and which any thread may run.  ``KernelProblem.measured`` provides
+      both for every kernel.  Within :meth:`compiling_ahead`, evaluating a
+      config then starts the compile of the next one on a thread, before
+      the device measures the current one.
+
+    A build that raises — the chip's compiler refusing the config — is one
+    invalid trial carrying the error, never a retry.  The objective is the
+    best of ``repeats`` timings, each ending in ``jax.block_until_ready``
+    so that it covers the device's work and not only the enqueue.
+    Analytical studies use the cost model instead (deterministic,
+    full-space-enumerable).
     """
 
     def __init__(self, space: SearchSpace,
-                 build: Callable[[Config], Callable[[], Any]],
-                 name: str = "measured", repeats: int = 5, warmup: int = 2):
+                 build: Callable[[Config], Callable[[], Any]] | None = None,
+                 name: str = "measured", repeats: int = 5, warmup: int = 2,
+                 *, lower: Callable[[Config], Any] | None = None,
+                 compile: Callable[[Any], Callable[[], Any]] | None = None):
         super().__init__(space)
+        if (build is None) == (lower is None or compile is None):
+            raise ValueError("give either build, or both lower and compile")
         self.build = build
+        self._lower, self._compile = lower, compile
         self.name = name
         self.repeats = repeats
         self.warmup = warmup
+        #: the compile-ahead plan of the thread that is measuring, if any
+        self._local = threading.local()
+
+    @property
+    def two_stage(self) -> bool:
+        """Whether the build comes as a lowering and a compile stage."""
+        return self.build is None
+
+    @contextlib.contextmanager
+    def compiling_ahead(self):
+        """Compile ahead, one config deep, in this thread's evaluations.
+
+        Yields a plan whose ``following`` the caller sets, before each
+        config it evaluates, to the config it will evaluate next.  Once
+        a config is built, :meth:`evaluate` lowers ``following`` on this
+        thread and hands its compile to one compile thread, then measures;
+        the next evaluation waits for that compile instead of building.
+        The compile thread lives as long as the block, which waits for the
+        compile in flight on the way out: a native compile cannot be
+        interrupted."""
+        if not self.two_stage:
+            raise ValueError("compiling ahead needs a build in two stages")
+        with ThreadPoolExecutor(
+                max_workers=1, thread_name_prefix="compile-ahead") as compiler:
+            plan = _CompileAhead(self, compiler)
+            self._local.plan = plan
+            try:
+                yield plan
+            finally:
+                self._local.plan = None
+
+    # the stages of a build; each span carries the config's key, which
+    # ties a config's spans together in a trace
+    def _lower_stage(self, config: Config) -> Any:
+        with span("kernel.lower", cat="kernel", key=config_key(config)):
+            return self._lower(config)
+
+    def _compile_stage(self, lowered: Any, config: Config,
+                       opened: threading.Event | None = None
+                       ) -> Callable[[], Any]:
+        with span("kernel.compile", cat="kernel", key=config_key(config),
+                  ahead=int(opened is not None)):
+            if opened is not None:
+                opened.set()
+            return self._compile(lowered)
+
+    def _built(self, config: Config, plan: "_CompileAhead | None"):
+        ready = plan.take(config) if plan is not None else None
+        if ready is not None:
+            return ready.result()
+        if self.build is not None:
+            return self.build(config)
+        return self._compile_stage(self._lower_stage(config), config)
 
     def evaluate(self, config: Config, arch: str = DEFAULT_ARCH) -> Trial:
         import jax
@@ -430,13 +500,16 @@ class MeasuredProblem(TunableProblem):
         # config's key, which ties them together in a trace.  Span overhead
         # sits outside the per-repeat perf_counter windows, so enabling
         # tracing cannot bias the recorded objective.
-        key = "/".join(f"{k}={config[k]}" for k in sorted(config))
+        key = config_key(config)
+        plan = getattr(self._local, "plan", None)
         try:
             with span("kernel.build", cat="kernel", arch=arch, key=key):
-                fn = self.build(config)
+                fn = self._built(config, plan)
         except Exception as e:  # config that fails to compile == invalid
             return Trial(config, math.inf, arch, valid=False,
                          info={"error": repr(e)})
+        if plan is not None:
+            plan.start()
         with span("kernel.measure", cat="kernel", arch=arch, key=key,
                   repeats=self.repeats,
                   calls=self.warmup + self.repeats) as s:
@@ -449,3 +522,50 @@ class MeasuredProblem(TunableProblem):
                 best = min(best, time.perf_counter() - t0)
             s.set(best_s=best)
         return Trial(config, best, arch, valid=True)
+
+
+class _CompileAhead:
+    """The plan of :meth:`MeasuredProblem.compiling_ahead`: at most one
+    compile in flight, for ``following``."""
+
+    def __init__(self, problem: MeasuredProblem, compiler: Executor):
+        self.problem, self.compiler = problem, compiler
+        #: the config the caller evaluates next, None after the last
+        self.following: Config | None = None
+        self._pending: tuple[Config, Future] | None = None
+
+    def take(self, config: Config) -> Future | None:
+        """The compile started ahead for ``config``; it is used once."""
+        if self._pending is None or self._pending[0] is not config:
+            return None
+        future, self._pending = self._pending[1], None
+        return future
+
+    def start(self) -> None:
+        """Lower ``following`` and start its compile, unless it has been
+        started.  A refusal at lowering is held in the future, until the
+        config's turn.  Returns once the compile's span is open on the
+        compile thread, so that a span this thread opens next is the
+        newer one."""
+        nxt = self.following
+        if (nxt is None or not self.problem.space.satisfies(nxt)
+                or (self._pending is not None and self._pending[0] is nxt)):
+            return
+        try:
+            lowered = self.problem._lower_stage(nxt)
+        except Exception as e:
+            future: Future = Future()
+            future.set_exception(e)
+        else:
+            opened = threading.Event()
+            future = self.compiler.submit(self.problem._compile_stage,
+                                          lowered, nxt, opened)
+            future.add_done_callback(lambda _: opened.set())
+            opened.wait()
+        self._pending = (nxt, future)
+
+
+def config_key(config: Config) -> str:
+    """``config`` as sorted ``k=v`` pairs joined by ``/``: the ``key`` of
+    its ``kernel.*`` spans."""
+    return "/".join(f"{k}={config[k]}" for k in sorted(config))

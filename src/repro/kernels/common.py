@@ -28,7 +28,6 @@ import numpy as np
 from ..core.costmodel import MiB
 from ..core.problem import MeasuredProblem, TunableProblem
 from ..core.space import Config, SearchSpace
-from ..telemetry.trace import span
 
 # Structural VMEM budget for space-level constraints: a config is kept in
 # the space if it could run on the LARGEST generation (128 MiB VMEM,
@@ -147,27 +146,33 @@ class KernelProblem(TunableProblem):
         return jax.jit(lambda a: self.run_kernel(
             config, {**consts, **a}, interpret=False)).lower(arrays)
 
-    def compile_kernel(self, config: Config, inputs: dict) -> Callable[[], Any]:
-        """Compile the kernel for ``inputs``, which live on the device, and
-        return a zero-argument call of the compiled program.  Raises what
-        the chip's compiler raises for a config it refuses."""
+    def compile_lowered(self, lowered: jax.stages.Lowered,
+                        inputs: dict) -> Callable[[], Any]:
+        """The backend compile of a :meth:`lower_kernel` result, which runs
+        in native code; returns a zero-argument call of the compiled
+        program on ``inputs``, which live on the device.  Raises what the
+        chip's compiler raises for a config it refuses."""
         arrays, _ = _split_inputs(inputs)
-        # tracing and lowering hold the interpreter lock; the backend
-        # compile runs in native code
-        with span("kernel.lower", cat="kernel"):
-            lowered = self.lower_kernel(config, inputs)
-        with span("kernel.compile", cat="kernel"):
-            compiled = lowered.compile()
+        compiled = lowered.compile()
         return lambda: compiled(arrays)
 
+    def compile_kernel(self, config: Config,
+                       inputs: dict) -> Callable[[], Any]:
+        """Lower and compile the kernel for ``inputs`` in one go: the two
+        stages of :meth:`measured`'s build, on this thread."""
+        return self.compile_lowered(self.lower_kernel(config, inputs), inputs)
+
     def measured(self, inputs: dict, **kw) -> MeasuredProblem:
-        """This kernel as a :class:`MeasuredProblem` on ``inputs``: each
-        config's build is :meth:`compile_kernel`, so a config the compiler
-        refuses becomes an invalid trial.  Turns on the compile cache."""
+        """This kernel as a :class:`MeasuredProblem` on ``inputs``, whose
+        build is :meth:`lower_kernel` then :meth:`compile_lowered`, so a
+        config the compiler refuses becomes an invalid trial.  Turns on the
+        compile cache."""
         use_compile_cache()
         return MeasuredProblem(
-            self.space, lambda config: self.compile_kernel(config, inputs),
-            name=self.name, **kw)
+            self.space, name=self.name,
+            lower=lambda config: self.lower_kernel(config, inputs),
+            compile=lambda lowered: self.compile_lowered(lowered, inputs),
+            **kw)
 
 
 def _split_inputs(inputs: dict) -> tuple[dict, dict]:

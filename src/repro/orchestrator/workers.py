@@ -30,6 +30,7 @@ the portability-campaign fast path.
 
 from __future__ import annotations
 
+import contextlib
 import json
 import math
 import threading
@@ -220,16 +221,27 @@ class WorkerPool:
         the pool's retry path; a config the compiler refuses is already an
         invalid trial (``MeasuredProblem.evaluate``) and costs one compile.
         The watchdog does not apply: a running measurement cannot be
-        interrupted from its own thread."""
+        interrupted from its own thread.
+
+        A batch of two configs or more, whose build has two stages,
+        compiles ahead (``MeasuredProblem.compiling_ahead``): the next
+        config's backend compile runs on a thread while this one measures
+        the current config.  A retry builds inline."""
         if self.mode == "process":
             raise ValueError(_MEASURED_IN_CHILD)
         out: list[Trial] = []
+        ahead = problem.two_stage and len(configs) > 1
         with span("pool.evaluate", cat="pool", n=len(configs), arch=arch,
-                  mode="inline"):
-            for cfg in configs:
+                  mode="inline"), \
+                (problem.compiling_ahead() if ahead
+                 else contextlib.nullcontext()) as plan:
+            for i, cfg in enumerate(configs):
                 if cancel is not None and cancel.is_set():
                     self.stats["cancelled"] += 1
                     raise EvalCancelled("batch abandoned (lease lost)")
+                if plan is not None:
+                    plan.following = (configs[i + 1] if i + 1 < len(configs)
+                                      else None)
                 for attempt in range(1, self.max_retries + 2):
                     try:
                         out.append(_evaluate_one(problem, cfg, arch))
