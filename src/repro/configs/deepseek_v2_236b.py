@@ -26,4 +26,4 @@ CONFIG = ModelConfig(
     rope_theta=10_000.0,
 )
 
-TUNABLE_KERNELS = ("gemm", "flash_attention")
+TUNABLE_KERNELS = ("gemm", "flash_attention", "mla_decode")

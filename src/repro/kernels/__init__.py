@@ -1,6 +1,8 @@
 """Tunable Pallas TPU kernels — the BAT 2.0 benchmark set, TPU-adapted.
 
-Seven paper kernels + flash attention, each a :class:`TunableProblem`.
+Seven paper kernels, flash attention and DeepSeek-V2's MLA decode
+(absorbed latent attention over a ragged batch of caches), each a
+:class:`TunableProblem`.
 """
 
 from .attention import AttentionProblem, flash_attention
@@ -9,6 +11,7 @@ from .dedisp import DedispProblem, dedisp
 from .expdist import ExpdistProblem, expdist
 from .hotspot import HotspotProblem, hotspot
 from .matmul import GemmProblem, gemm
+from .mla import MLADecodeProblem, mla_decode
 from .nbody import NbodyProblem, nbody
 from .pnpoly import PnpolyProblem, pnpoly
 
@@ -22,6 +25,7 @@ BENCHMARKS = {
     "expdist": ExpdistProblem,
     "dedisp": DedispProblem,
     "flash_attention": AttentionProblem,
+    "mla_decode": MLADecodeProblem,
 }
 
 #: paper protocol: exhaustive where tractable, 10k samples otherwise
@@ -30,5 +34,6 @@ EXHAUSTIVE = ("pnpoly", "nbody", "gemm", "conv2d", "flash_attention")
 __all__ = ["BENCHMARKS", "EXHAUSTIVE", "GemmProblem", "Conv2dProblem",
            "NbodyProblem", "HotspotProblem", "PnpolyProblem",
            "ExpdistProblem", "DedispProblem", "AttentionProblem",
+           "MLADecodeProblem",
            "gemm", "conv2d", "nbody", "hotspot", "pnpoly", "expdist",
-           "dedisp", "flash_attention"]
+           "dedisp", "flash_attention", "mla_decode"]

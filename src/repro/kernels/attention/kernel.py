@@ -31,6 +31,29 @@ from ..common import cdiv
 NEG_INF = -1e30
 
 
+def online_softmax_update(s, v, m_ref, l_ref, acc_ref, idx):
+    """One key/value tile of the online softmax.
+
+    ``s``: the tile's f32 scores, rows by keys; ``v``: its values, keys by
+    width.  ``m_ref``, ``l_ref`` and ``acc_ref`` hold the running row max,
+    denominator and weighted sum; ``idx`` picks the rows' slot in them (a
+    head index, or ``...``).  The old sum and denominator are rescaled by
+    ``exp(m_prev - m_new)`` and the tile's ``p = exp(s - m_new)`` is added;
+    ``p`` enters the MXU in ``v``'s dtype, with f32 accumulation."""
+    m_prev = m_ref[idx].astype(jnp.float32)           # (rows, 1)
+    m_cur = s.max(axis=1, keepdims=True)
+    m_new = jnp.maximum(m_prev, m_cur)
+    alpha = jnp.exp(m_prev - m_new)
+    p = jnp.exp(s - m_new)
+    l_ref[idx] = (alpha * l_ref[idx].astype(jnp.float32)
+                  + p.sum(axis=1, keepdims=True)).astype(l_ref.dtype)
+    pv = jax.lax.dot_general(p.astype(v.dtype), v, (((1,), (0,)), ((), ())),
+                             preferred_element_type=jnp.float32)
+    acc_ref[idx] = (acc_ref[idx].astype(jnp.float32) * alpha
+                    + pv).astype(acc_ref.dtype)
+    m_ref[idx] = m_new.astype(m_ref.dtype)
+
+
 def _flash_kernel(q_ref, k_ref, v_ref, o_ref, m_ref, l_ref, acc_ref, *,
                   scale, causal, block_q, block_kv, block_h, tq, tk,
                   nkv_grid, skip_masked):
@@ -57,18 +80,7 @@ def _flash_kernel(q_ref, k_ref, v_ref, o_ref, m_ref, l_ref, acc_ref, *,
                                     preferred_element_type=jnp.float32) * scale
             if causal:
                 s = jnp.where(rows0 >= cols0, s, NEG_INF)
-            m_prev = m_ref[hh].astype(jnp.float32)    # (bq, 1)
-            m_cur = s.max(axis=1, keepdims=True)
-            m_new = jnp.maximum(m_prev, m_cur)
-            alpha = jnp.exp(m_prev - m_new)
-            p = jnp.exp(s - m_new)
-            l_ref[hh] = (alpha * l_ref[hh].astype(jnp.float32)
-                         + p.sum(axis=1, keepdims=True)).astype(l_ref.dtype)
-            pv = jax.lax.dot_general(p, v, (((1,), (0,)), ((), ())),
-                                     preferred_element_type=jnp.float32)
-            acc_ref[hh] = (acc_ref[hh].astype(jnp.float32) * alpha
-                           + pv).astype(acc_ref.dtype)
-            m_ref[hh] = m_new.astype(m_ref.dtype)
+            online_softmax_update(s, v, m_ref, l_ref, acc_ref, hh)
 
     if causal and skip_masked:
         # last row of this q tile vs first col of this kv tile: if even that

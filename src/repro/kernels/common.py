@@ -55,6 +55,7 @@ REL_L2_TOL = {
     "expdist": (1e-3, 2e-2),
     "dedisp": (1e-3, 2e-2),
     "flash_attention": (5e-3, 2e-2),
+    "mla_decode": (5e-3, 2e-2),
 }
 
 

@@ -29,6 +29,7 @@ PROBLEM_PATHS: dict[str, str] = {
     "dedisp": "repro.kernels.dedisp.space:DedispProblem",
     "expdist": "repro.kernels.expdist.space:ExpdistProblem",
     "attention": "repro.kernels.attention.space:AttentionProblem",
+    "mla_decode": "repro.kernels.mla.space:MLADecodeProblem",
 }
 
 

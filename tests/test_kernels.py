@@ -18,6 +18,7 @@ from repro.kernels.dedisp.space import DedispProblem
 from repro.kernels.expdist.space import ExpdistProblem
 from repro.kernels.hotspot.space import HotspotProblem
 from repro.kernels.matmul.space import GemmProblem
+from repro.kernels.mla.space import MLADecodeProblem
 from repro.kernels.nbody.space import NbodyProblem
 from repro.kernels.pnpoly.space import PnpolyProblem
 
@@ -30,6 +31,7 @@ PROBLEMS = {
     "expdist": ExpdistProblem,
     "dedisp": DedispProblem,
     "attention": AttentionProblem,
+    "mla_decode": MLADecodeProblem,
 }
 
 N_CONFIGS = 4          # sampled tunable configs per kernel

@@ -475,7 +475,7 @@ def test_audit_clean_space_ok():
 
 @pytest.mark.parametrize("name", [
     "gemm", "conv2d", "pnpoly", "nbody", "hotspot", "dedisp", "expdist",
-    "attention", "toy_quad", "toy_rastrigin"])
+    "attention", "mla_decode", "toy_quad", "toy_rastrigin"])
 def test_all_shipped_spaces_pass_audit(name):
     from repro.orchestrator.registry import make_problem
     rep = audit_space(make_problem(name).space)

@@ -5,8 +5,10 @@ The TPU compiler is installed with JAX and compiles for a described
 ``v5e:2x2`` topology without a chip, so these tests see what interpret mode
 cannot: block shapes the chip refuses, VMEM over-use, Pallas ops with no
 Mosaic lowering.  Each test compiles one kernel at its ``default_shape``
-(flash attention at qwen3-8b's widths, GEMM 4096^3 bf16, nbody, hotspot)
-with its ``ops.py`` default config, exactly as ``chip_smoke.py`` runs it.
+(flash attention at qwen3-8b's widths, GEMM 4096^3 bf16, nbody, hotspot,
+MLA decode at DeepSeek-V2's widths over 64 sequences of a 64k-token cache)
+with its ``ops.py`` default config; the first four exactly as
+``chip_smoke.py`` runs them, MLA decode as ``dsv2-mla-dec64.deploy`` does.
 
 The topology is described inside a module fixture, never at import: only
 one process may load the TPU library, and every test worker imports this
@@ -33,6 +35,8 @@ from repro.kernels.hotspot import ops as hotspot_ops
 from repro.kernels.hotspot.space import HotspotProblem
 from repro.kernels.matmul import ops as matmul_ops
 from repro.kernels.matmul.space import GemmProblem
+from repro.kernels.mla import ops as mla_ops
+from repro.kernels.mla.space import MLADecodeProblem
 from repro.kernels.nbody import ops as nbody_ops
 from repro.kernels.nbody.space import NbodyProblem
 from repro.kernels.pnpoly import ops as pnpoly_ops
@@ -44,6 +48,7 @@ MAIN_PATH = {
     "gemm": (GemmProblem, matmul_ops.DEFAULT_CONFIG),
     "nbody": (NbodyProblem, nbody_ops.DEFAULT_CONFIG),
     "hotspot": (HotspotProblem, hotspot_ops.DEFAULT_CONFIG),
+    "mla_decode": (MLADecodeProblem, mla_ops.DEFAULT_CONFIG),
 }
 
 #: kernels the v5e compiler refuses at their defaults, with the words of
