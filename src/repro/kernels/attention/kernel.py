@@ -1,16 +1,21 @@
 """Tunable Pallas TPU flash attention (GQA-aware, causal-capable).
 
 Online-softmax tiling (FlashAttention adapted to the TPU memory hierarchy):
-(block_q × d) query tiles stay VMEM-resident while (block_kv × d) key/value
-tiles stream; running max/denominator in VMEM scratch.  GQA is expressed in
-the BlockSpec index maps (kv head = q head // group), so no KV replication
-ever materializes.
+a program's (block_h · block_q × d) query rows — block_q rows of each of
+block_h heads that share one kv head — stay VMEM-resident while (block_kv ×
+d) key/value tiles stream; running max/denominator per row in VMEM scratch.
+GQA is expressed in the BlockSpec index maps (kv head = q head // group), so
+no KV replication ever materializes.
 
 Tunables (the TPU vocabulary for attention):
 
   block_q / block_kv — VMEM tile shape (arithmetic-intensity vs residency),
-  block_h            — q heads per program; GQA heads sharing a kv head can
-                       amortize each streamed K/V tile (requires block_h | g),
+  block_h            — q heads per program, all of one GQA group: their
+                       queries are the rows of one MXU tile, so each streamed
+                       K/V tile serves block_h heads at once (the kernel takes
+                       the largest divisor of g not above block_h; the
+                       deployed op asks for 8, so it takes the whole group
+                       where g <= 8),
   skip_masked        — causal block skipping: fully-masked kv tiles do no
                        compute (grid still visits them; on hardware this
                        halves the MXU work of causal attention),
@@ -59,6 +64,8 @@ def _flash_kernel(q_ref, k_ref, v_ref, o_ref, m_ref, l_ref, acc_ref, *,
                   nkv_grid, skip_masked):
     j = pl.program_id(2)
     qi = pl.program_id(1)
+    rows = block_h * block_q
+    d = q_ref.shape[-1]
 
     @pl.when(j == 0)
     def _init():
@@ -67,20 +74,22 @@ def _flash_kernel(q_ref, k_ref, v_ref, o_ref, m_ref, l_ref, acc_ref, *,
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
     def body():
+        # the block_h heads' queries are the rows of one tile: one dot
+        # against the K tile and one against the V tile serve them all
+        q = q_ref[...].astype(jnp.float32).reshape(rows, d)
         k = k_ref[0].astype(jnp.float32)              # (bkv, d)
         v = v_ref[0].astype(jnp.float32)
+        s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
+                                preferred_element_type=jnp.float32) * scale
         if causal:
-            rows0 = qi * block_q + jax.lax.broadcasted_iota(
-                jnp.int32, (block_q, block_kv), 0) + (tk - tq)
-            cols0 = j * block_kv + jax.lax.broadcasted_iota(
-                jnp.int32, (block_q, block_kv), 1)
-        for hh in range(block_h):                     # GQA: amortize K/V tile
-            q = q_ref[hh].astype(jnp.float32)         # (bq, d)
-            s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
-                                    preferred_element_type=jnp.float32) * scale
-            if causal:
-                s = jnp.where(rows0 >= cols0, s, NEG_INF)
-            online_softmax_update(s, v, m_ref, l_ref, acc_ref, hh)
+            # a row's query position is its place among its head's rows
+            pos = jax.lax.broadcasted_iota(
+                jnp.int32, (block_h, block_q, block_kv), 1).reshape(
+                    rows, block_kv)
+            cols = j * block_kv + jax.lax.broadcasted_iota(
+                jnp.int32, (rows, block_kv), 1)
+            s = jnp.where(qi * block_q + pos + (tk - tq) >= cols, s, NEG_INF)
+        online_softmax_update(s, v, m_ref, l_ref, acc_ref, ...)
 
     if causal and skip_masked:
         # last row of this q tile vs first col of this kv tile: if even that
@@ -92,10 +101,25 @@ def _flash_kernel(q_ref, k_ref, v_ref, o_ref, m_ref, l_ref, acc_ref, *,
 
     @pl.when(j == nkv_grid - 1)
     def _finish():
-        for hh in range(block_h):
-            o_ref[hh] = (acc_ref[hh].astype(jnp.float32)
-                         / jnp.maximum(l_ref[hh].astype(jnp.float32), 1e-30)
-                         ).astype(o_ref.dtype)
+        o = (acc_ref[...].astype(jnp.float32)
+             / jnp.maximum(l_ref[...], 1e-30))
+        o_ref[...] = o.reshape(block_h, block_q, d).astype(o_ref.dtype)
+
+
+def tiling(q_shape, k_shape, *, block_q, block_kv, block_h):
+    """The blocks and the grid the kernel runs for ``q`` and ``k`` of these
+    shapes: ``((bh, bq, bkv), grid)``.  ``bh`` is the largest divisor of the
+    GQA group ``Hq // Hkv`` not above ``block_h``; the grid is ``(Hq / bh,
+    Tq / bq, Tk / bkv)``, one step per (head block, q tile, kv tile)."""
+    hq, tq, _ = q_shape
+    hkv, tk, _ = k_shape
+    g = hq // hkv
+    bh = max(1, min(block_h, g))
+    while g % bh:
+        bh -= 1
+    bq = min(block_q, tq)
+    bkv = min(block_kv, tk)
+    return (bh, bq, bkv), (hq // bh, cdiv(tq, bq), cdiv(tk, bkv))
 
 
 @functools.partial(
@@ -106,26 +130,23 @@ def flash_attention(q, k, v, *, causal=True, block_q=256, block_kv=512,
                     block_h=1, skip_masked=1, acc_dtype="f32", scale=None,
                     interpret=False):
     """``q``: (Hq, Tq, D); ``k``/``v``: (Hkv, Tk, D).  Returns (Hq, Tq, D).
-    ``block_h`` must divide the GQA group size Hq // Hkv."""
+    A program takes the largest divisor of the GQA group Hq // Hkv not above
+    ``block_h`` (:func:`tiling`)."""
     hq, tq, d = q.shape
     hkv, tk, _ = k.shape
     g = hq // hkv
-    bh = max(1, min(block_h, g))
-    while g % bh:
-        bh -= 1
-    bq = min(block_q, tq)
-    bkv = min(block_kv, tk)
+    (bh, bq, bkv), grid = tiling(q.shape, k.shape, block_q=block_q,
+                                 block_kv=block_kv, block_h=block_h)
     scale = float(scale) if scale is not None else float(d) ** -0.5
     acc_jnp = jnp.float32 if acc_dtype == "f32" else jnp.bfloat16
 
     kern = functools.partial(
         _flash_kernel, scale=scale, causal=causal, block_q=bq, block_kv=bkv,
-        block_h=bh, tq=tq, tk=tk, nkv_grid=cdiv(tk, bkv),
-        skip_masked=skip_masked)
+        block_h=bh, tq=tq, tk=tk, nkv_grid=grid[2], skip_masked=skip_masked)
     return pl.pallas_call(
         kern,
         name="flash_attention",
-        grid=(hq // bh, cdiv(tq, bq), cdiv(tk, bkv)),
+        grid=grid,
         in_specs=[
             pl.BlockSpec((bh, bq, d), lambda h, i, j: (h, i, 0)),
             pl.BlockSpec((1, bkv, d), lambda h, i, j, bh=bh, g=g:
@@ -136,9 +157,9 @@ def flash_attention(q, k, v, *, causal=True, block_q=256, block_kv=512,
         out_specs=pl.BlockSpec((bh, bq, d), lambda h, i, j: (h, i, 0)),
         out_shape=jax.ShapeDtypeStruct((hq, tq, d), q.dtype),
         scratch_shapes=[
-            pltpu.VMEM((bh, bq, 1), jnp.float32),
-            pltpu.VMEM((bh, bq, 1), jnp.float32),
-            pltpu.VMEM((bh, bq, d), acc_jnp),
+            pltpu.VMEM((bh * bq, 1), jnp.float32),
+            pltpu.VMEM((bh * bq, 1), jnp.float32),
+            pltpu.VMEM((bh * bq, d), acc_jnp),
         ],
         interpret=interpret,
     )(q, k, v)

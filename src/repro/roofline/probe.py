@@ -296,6 +296,7 @@ def attention_substitution(cfg, b: int, t: int, mesh: Mesh, *, train: bool,
     Substituting the kernel's terms for the jnp chain's is how the framework
     composes graph-level and kernel-level rooflines.  Only applied under
     ``opt_attn`` (the baseline keeps the faithful jnp lowering)."""
+    from ..kernels.attention.kernel import tiling
     from ..kernels.attention.ops import DEFAULT_CONFIG
     from ..kernels.attention.space import AttentionProblem
     from ..models import attention as attn_lib
@@ -338,7 +339,12 @@ def attention_substitution(cfg, b: int, t: int, mesh: Mesh, *, train: bool,
     # --- substituted: tuned flash-kernel terms (suite cost features) ------ #
     prob = AttentionProblem(shape={"hq": b * h, "hkv": b * hkv,
                                    "tq": t, "tk": t, "d": dh})
-    feats = prob.features(dict(DEFAULT_CONFIG), "v5e")
+    # the heads a program takes at this group, as the kernel resolves them
+    (bh, _, _), _ = tiling((b * h, t, dh), (b * hkv, t, dh),
+                           block_q=DEFAULT_CONFIG["block_q"],
+                           block_kv=DEFAULT_CONFIG["block_kv"],
+                           block_h=DEFAULT_CONFIG["block_h"])
+    feats = prob.features({**DEFAULT_CONFIG, "block_h": bh}, "v5e")
     fl = feats.mxu_flops + feats.vpu_flops + feats.transcendental_ops
     hb = feats.hbm_bytes
     if window and window < t // 2:      # local layers do ~t*w work

@@ -83,14 +83,18 @@ def test_gemm_shape_sweep():
                                    rtol=3e-2, atol=3e-2)
 
 
-def test_attention_causal_and_full():
-    prob = AttentionProblem()
-    cfg = {"block_q": 64, "block_kv": 128}
+@pytest.mark.parametrize("hq,hkv,block_h", [(4, 2, 1), (14, 2, 7),
+                                             (12, 2, 6)])
+def test_attention_causal_and_full(hq, hkv, block_h):
+    """Groups of 7 and 6 fold their heads into the rows of one tile; the
+    causal mask must follow each row's place within its own head (tq 128
+    < tk 256 puts the diagonal at an offset)."""
+    cfg = {"block_q": 64, "block_kv": 128, "block_h": block_h}
     from repro.kernels.attention.kernel import flash_attention
     from repro.kernels.attention.ref import mha_reference
-    q = jax.random.normal(jax.random.key(0), (4, 128, 64), jnp.float32)
-    k = jax.random.normal(jax.random.key(1), (2, 256, 64), jnp.float32)
-    v = jax.random.normal(jax.random.key(2), (2, 256, 64), jnp.float32)
+    q = jax.random.normal(jax.random.key(0), (hq, 128, 64), jnp.float32)
+    k = jax.random.normal(jax.random.key(1), (hkv, 256, 64), jnp.float32)
+    v = jax.random.normal(jax.random.key(2), (hkv, 256, 64), jnp.float32)
     for causal in (False, True):
         got = flash_attention(q, k, v, causal=causal, interpret=True, **cfg)
         want = mha_reference(q, k, v, causal=causal)

@@ -9,6 +9,8 @@ Mosaic lowering.  Each test compiles one kernel at its ``default_shape``
 MLA decode at DeepSeek-V2's widths over 64 sequences of a 64k-token cache)
 with its ``ops.py`` default config; the first four exactly as
 ``chip_smoke.py`` runs them, MLA decode as ``dsv2-mla-dec64.deploy`` does.
+The attention op's default also compiles at DeepSeek-Coder-33B's group of 7
+(as ``dsc33b-attn8k.deploy`` calls it) and at a group of 8.
 
 The topology is described inside a module fixture, never at import: only
 one process may load the TPU library, and every test worker imports this
@@ -20,10 +22,12 @@ from __future__ import annotations
 import os
 
 import jax
+import jax.numpy as jnp
 import pytest
 from jax.sharding import SingleDeviceSharding
 
 from repro.kernels.attention import ops as attention_ops
+from repro.kernels.attention.kernel import tiling
 from repro.kernels.attention.space import AttentionProblem
 from repro.kernels.conv2d import ops as conv2d_ops
 from repro.kernels.conv2d.space import Conv2dProblem
@@ -125,6 +129,24 @@ def test_attention_vmem_overuse_is_refused(one_chip):
     with pytest.raises(Exception,
                        match="Ran out of memory in memory space vmem"):
         _compile(prob, VMEM_REFUSED, one_chip)
+
+
+@pytest.mark.parametrize("hq", [56, 64])
+def test_attention_default_takes_the_whole_group(hq, one_chip):
+    """The deployed op at DeepSeek-Coder-33B's prefill (56 query heads over
+    8 kv heads, a group of 7, 8k tokens) and at a group of 8 compiles with
+    one program per kv head: the grid is (8, tq / bq, tk / bkv)."""
+    t, d = 8192, 128
+    q = jax.ShapeDtypeStruct((hq, t, d), jnp.bfloat16, sharding=one_chip)
+    kv = jax.ShapeDtypeStruct((8, t, d), jnp.bfloat16, sharding=one_chip)
+    cfg = attention_ops.DEFAULT_CONFIG
+    (bh, bq, bkv), grid = tiling(q.shape, kv.shape, block_q=cfg["block_q"],
+                                 block_kv=cfg["block_kv"],
+                                 block_h=cfg["block_h"])
+    assert bh == hq // 8
+    assert grid == (8, t // bq, t // bkv)
+    compiled = jax.jit(attention_ops.attention).lower(q, kv, kv).compile()
+    assert "tpu_custom_call" in compiled.as_text()
 
 
 @pytest.mark.parametrize("name", sorted(REFUSED))
